@@ -115,7 +115,7 @@ def _relax_jax(entry: np.ndarray, src: np.ndarray, dst: np.ndarray,
         floor, _ = jax.lax.while_loop(cond, body, (floor0, jnp.bool_(True)))
         return floor
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return np.asarray(run(jnp.asarray(entry), jnp.asarray(src),
                               jnp.asarray(dst), jnp.asarray(w)))
 
@@ -132,17 +132,9 @@ def build_static_floors(links: List) -> List[int]:
     src, dst, w, foreign_fed = _edges(links)
     if foreign_fed:
         entry[list(foreign_fed)] = True
-    use_jax = os.environ.get("REPRO_LEDGER_JAX") == "1"
-    relax = _relax_numpy
-    if use_jax:
-        try:
-            relax = _relax_jax
-        except Exception:           # pragma: no cover - defensive
-            relax = _relax_numpy
-    try:
-        floor = relax(entry, src, dst, w)
-    except Exception:               # pragma: no cover - jax unavailable
-        floor = _relax_numpy(entry, src, dst, w)
+    relax = _relax_jax if os.environ.get("REPRO_LEDGER_JAX") == "1" \
+        else _relax_numpy
+    floor = relax(entry, src, dst, w)
     # the per-link result is the cone floor at the link's *input*: min
     # over feeder edges of (feeder floor + feeder transit), independent of
     # the link's own entry status (its own resv/inj terms stay dynamic)

@@ -21,13 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU backend params are importable on CPU for interpret mode
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -109,22 +103,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     o_spec = pl.BlockSpec((1, 1, block_q, D),
                           lambda b, h, qi, ki: (b, h, qi, 0))
     scratch = [
-        _VMEM((block_q, 1), jnp.float32),
-        _VMEM((block_q, 1), jnp.float32),
-        _VMEM((block_q, D), jnp.float32),
-    ] if _VMEM is not None else []
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, D), jnp.float32),
+    ]
 
     kern = functools.partial(_kernel, block_q=block_q, block_k=block_k,
                              nk=nk, causal=causal, window=window,
                              scale=scale, kv_len=kv_len)
-    params = {}
-    if pltpu is not None and not interpret:
-        try:
-            params["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary"))
-        except Exception:  # older API name
-            pass
     return pl.pallas_call(
         kern,
         grid=grid,
@@ -132,6 +118,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-        **params,
     )(q, k, v)

@@ -18,13 +18,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+
+_ROWS = 16
 
 
 def _kernel(a_ref, b_ref, h0_ref, y_ref, h_ref, *, block_t: int):
@@ -32,19 +29,23 @@ def _kernel(a_ref, b_ref, h0_ref, y_ref, h_ref, *, block_t: int):
 
     @pl.when(ti == 0)
     def _init():
-        h_ref[...] = h0_ref[...].astype(jnp.float32)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    a = a_ref[0].astype(jnp.float32)     # (block_t, block_r)
-    b = b_ref[0].astype(jnp.float32)
-
-    def step(t, h):                      # h: (1, block_r)
-        at = jax.lax.dynamic_slice_in_dim(a, t, 1, axis=0)
-        bt = jax.lax.dynamic_slice_in_dim(b, t, 1, axis=0)
-        h = at * h + bt
-        y_ref[0, pl.ds(t, 1), :] = h.astype(y_ref.dtype)
+    def step(g, h):                      # h: (1, block_r)
+        # load and store whole (_ROWS, block_r) tiles: Mosaic needs dynamic
+        # sublane offsets aligned to the tile (16 rows covers bf16 too)
+        t0 = pl.multiple_of(g * _ROWS, _ROWS)
+        a = a_ref[0, pl.ds(t0, _ROWS), :].astype(jnp.float32)
+        b = b_ref[0, pl.ds(t0, _ROWS), :].astype(jnp.float32)
+        hs = []
+        for i in range(_ROWS):
+            h = a[i:i + 1] * h + b[i:i + 1]
+            hs.append(h)
+        y_ref[0, pl.ds(t0, _ROWS), :] = \
+            jnp.concatenate(hs, axis=0).astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_t, step, h_ref[...])
+    h = jax.lax.fori_loop(0, block_t // _ROWS, step, h_ref[...])
     h_ref[...] = h
 
 
@@ -55,25 +56,21 @@ def rg_lru_scan(a, b, h0, *, block_t: int = 128, block_r: int = 512,
     block_t = min(block_t, T)
     block_r = min(block_r, R)
     assert T % block_t == 0 and R % block_r == 0, (T, R, block_t, block_r)
+    assert block_t % _ROWS == 0, (block_t, _ROWS)
     grid = (B, R // block_r, T // block_t)
     spec = pl.BlockSpec((1, block_t, block_r),
                         lambda bb, ri, ti: (bb, ti, ri))
-    h0_spec = pl.BlockSpec((1, block_r), lambda bb, ri, ti: (bb, ri))
-    scratch = [_VMEM((1, block_r), jnp.float32)] if _VMEM is not None else []
-    params = {}
-    if pltpu is not None and not interpret:
-        try:
-            params["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:
-            pass
+    # h0 as (B, 1, R): a (1, block_r) block then spans the array's
+    # second-minor dim, as the TPU's (8, 128) tiling rule requires
+    h0_spec = pl.BlockSpec((1, 1, block_r), lambda bb, ri, ti: (bb, 0, ri))
     kern = functools.partial(_kernel, block_t=block_t)
     return pl.pallas_call(
         kern, grid=grid,
         in_specs=[spec, spec, h0_spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, T, R), a.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **params,
-    )(a, b, h0)
+    )(a, b, h0.reshape(B, 1, R))

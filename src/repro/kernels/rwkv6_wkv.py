@@ -18,13 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_ref, *,
@@ -42,17 +36,23 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_ref, *,
     u = u_ref[0].astype(jnp.float32)             # (1, N) -> broadcast
     S = state_ref[...]                           # (N, N)
 
-    c = jnp.cumsum(w, axis=0)                    # inclusive
+    # inclusive cumsum over the chunk as a lower-triangular matmul (Mosaic
+    # has no cumsum); HIGHEST keeps the f32 log-decays exact enough to exp
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >=
+           jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1))
+    c = jax.lax.dot_general(tri.astype(jnp.float32), w,
+                            (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     c_prev = c - w                               # exclusive
     c_end = c[-1:]                               # (1, N)
 
     # intra-chunk scores[t,s] = sum_n r[t,n] k[s,n] exp(c_prev[t]-c[s]) s<t
     expo = c_prev[:, None, :] - c[None, :, :]    # (C, C, N)
-    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) > \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    expo = jnp.where(mask[:, :, None], expo, -jnp.inf)
-    scores = jnp.einsum("tn,sn,tsn->ts", r, k, jnp.exp(expo),
-                        preferred_element_type=jnp.float32)
+    mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk, n), 0) > \
+        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk, n), 1)
+    expo = jnp.where(mask, expo, -jnp.inf)
+    scores = jnp.sum(r[:, None, :] * k[None, :, :] * jnp.exp(expo), axis=-1)
     y = jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     # bonus diagonal term: (r . (u*k)) v
@@ -78,22 +78,17 @@ def wkv6(r, k, v, logw, u, *, chunk: int = 32, interpret: bool = False):
     nc = T // chunk
     grid = (B, H, nc)
     spec = pl.BlockSpec((1, 1, chunk, N), lambda b, h, c: (b, h, c, 0))
-    u_spec = pl.BlockSpec((1, N), lambda b, h, c: (h, 0))
-    scratch = [_VMEM((N, N), jnp.float32)] if _VMEM is not None else []
-    params = {}
-    if pltpu is not None and not interpret:
-        try:
-            params["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except Exception:
-            pass
+    # u as (H, 1, N): a (1, N) block then spans the array's last two dims,
+    # as the TPU's (8, 128) tiling rule requires
+    u_spec = pl.BlockSpec((1, 1, N), lambda b, h, c: (h, 0, 0))
     kern = functools.partial(_kernel, chunk=chunk, n=N)
     return pl.pallas_call(
         kern, grid=grid,
         in_specs=[spec, spec, spec, spec, u_spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, N), r.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **params,
-    )(r, k, v, logw, u)
+    )(r, k, v, logw, u.reshape(H, 1, N))

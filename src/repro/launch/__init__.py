@@ -1,3 +1,3 @@
-from .mesh import make_cpu_mesh, make_production_mesh
+from .mesh import auto_mesh, make_device_mesh, make_production_mesh
 
-__all__ = ["make_production_mesh", "make_cpu_mesh"]
+__all__ = ["auto_mesh", "make_production_mesh", "make_device_mesh"]

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this builds the jit-ed step (train_step for train shapes,
@@ -17,17 +14,13 @@ Usage:
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
 from functools import partial
 
 import jax
-
-# persistent compilation cache: retries and perf iterations on unchanged
-# cells hit the cache instead of recompiling
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 10)
 import jax.numpy as jnp
 
 from ..analysis.hlo_stats import analyze as analyze_hlo
@@ -38,7 +31,9 @@ from ..configs.base import SHAPES, get, registry
 from ..distributed import sharding as shard
 from ..models import api
 from ..optim.adamw import AdamWConfig
-from ..train.step import init_train_state, make_serve_step, make_train_step
+from ..train.step import (init_train_state, make_prefill_step,
+                          make_serve_step, make_train_step)
+from .common import init_compile_cache
 from .mesh import make_production_mesh
 
 REPLICATED = None  # alias for readability
@@ -79,12 +74,10 @@ def build_cell(arch: str, shape_name: str, mesh, plan: str = "default"):
     params_in = shard.with_sharding(params_abs, p_specs, mesh)
 
     if shape.kind == "prefill":
-        def prefill_fn(params, batch):
-            return api.prefill(params, cfg, batch)
         b_specs = shard.batch_specs(specs, cfg, mesh)
         batch_in = shard.with_sharding(specs, b_specs, mesh)
         jitted = jax.jit(
-            prefill_fn,
+            make_prefill_step(cfg),
             in_shardings=(shard.to_named(p_specs, mesh),
                           shard.to_named(b_specs, mesh)))
         return jitted, (params_in, batch_in)
@@ -167,6 +160,12 @@ def main(argv=None):
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
 
+    # the production meshes need 512 devices: host devices, set before JAX
+    # starts its backend
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        "--xla_force_host_platform_device_count=512",
+        os.environ.get("XLA_FLAGS")]))
+    init_compile_cache()
     cells = []
     if args.all:
         for arch in sorted(registry()):

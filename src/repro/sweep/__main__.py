@@ -12,6 +12,8 @@ import argparse
 import os
 import sys
 
+# simulator-only entry points pin the CPU because they must never take
+# the chip
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

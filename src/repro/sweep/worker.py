@@ -9,8 +9,8 @@ the worker recomputes the point's content-addressed key and the parent
 compares it against its own — a mismatch means ``build`` is
 nondeterministic and the cache would lie.
 
-``JAX_PLATFORMS=cpu`` is pinned before anything imports jax; without it,
-forked workers re-probe accelerators, which masquerades as a hang.
+``JAX_PLATFORMS=cpu`` is pinned before anything imports jax: simulator-only
+entry points pin the CPU because they must never take the chip.
 """
 
 from __future__ import annotations

@@ -1,12 +1,12 @@
 """train_step / serve_step factories — the jit roots of the framework.
 
 These are what ``launch/dryrun.py`` lowers for every (arch x shape x mesh)
-cell and what ``launch/train.py`` runs for real on CPU smoke scales.
+cell and what ``launch/train.py`` and ``launch/serve.py`` run.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +59,23 @@ def make_serve_step(cfg: ArchConfig):
     return serve_step
 
 
-def make_prefill_step(cfg: ArchConfig):
+def make_prefill_step(cfg: ArchConfig, max_len: Optional[int] = None):
+    """prefill_step(params, batch) -> (cache, last-position logits).
+
+    With ``max_len``, a key/value cache comes back padded to ``max_len``
+    positions: the decode cache ``serve_step`` continues from."""
+    kv_cache = cfg.family in ("dense", "moe", "vlm", "encdec")
+
     def prefill_step(params, batch):
-        return api.prefill(params, cfg, batch)
+        cache, logits = api.prefill(params, cfg, batch)
+        if max_len is not None and kv_cache:
+            cache = dict(cache)
+            for name in ("k", "v"):        # (L, B, S, Kh, Dh)
+                c = cache[name]
+                pad = [(0, 0)] * c.ndim
+                pad[2] = (0, max_len - c.shape[2])
+                cache[name] = jnp.pad(c, pad)
+        return cache, logits
 
     return prefill_step
 

@@ -14,6 +14,7 @@ from repro.data.pipeline import PipelineConfig, TokenPipeline
 from repro.distributed import hints
 from repro.distributed import sharding as shard
 from repro.distributed.checkpoint import CheckpointManager
+from repro.launch.mesh import auto_mesh
 from repro.models import api
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
@@ -22,7 +23,7 @@ from repro.train.step import init_train_state, make_train_step
 
 def test_param_specs_cover_full_llama_tree():
     cfg = get("llama3-8b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     abs_params = jax.eval_shape(
         lambda k: api.init_params(k, cfg), jax.random.PRNGKey(0))
     specs = shard.params_specs(abs_params, cfg, mesh)
@@ -77,7 +78,7 @@ def test_constrain_noop_without_mesh():
 
 
 def test_constrain_drops_indivisible_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     with hints.use_mesh(mesh):
         x = jnp.ones((3, 5))
         y = hints.constrain(x, "data", "model")  # 3 % 1 == 0 -> kept
@@ -86,7 +87,7 @@ def test_constrain_drops_indivisible_axes():
 
 def test_sharded_train_step_runs_on_cpu_mesh():
     cfg = reduced(get("llama3-8b"))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("t", 32, 2, "train")
     with hints.use_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(0), cfg)
@@ -131,7 +132,7 @@ def test_elastic_restore_resharding(tmp_path):
     cm = CheckpointManager(str(tmp_path))
     x = jnp.arange(16.0).reshape(4, 4)
     cm.save(1, {"w": x})
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = auto_mesh((1, 1), ("data", "model"))
     from jax.sharding import NamedSharding
     sh = {"w": NamedSharding(mesh2, P("data", None))}
     _, tree = cm.restore(shardings=sh)

@@ -243,3 +243,17 @@ def test_chan_clock_threshold_is_monotone_in_need():
                     assert fab.clock_ge_ps(link, base + delta // 2), \
                         "threshold query must be monotone in need"
     assert fab.order_violations == 0
+
+
+def test_jax_relaxation_matches_numpy():
+    """``REPRO_LEDGER_JAX=1`` runs the static-floor fixpoint as a jitted
+    JAX loop; it must give the NumPy floors exactly, cycles included."""
+    import numpy as np
+    from repro.core.network import ledger_tables as lt
+    rng = np.random.default_rng(0)
+    n, m = 40, 120
+    entry = rng.random(n) < 0.2
+    src, dst = rng.integers(0, n, m), rng.integers(0, n, m)
+    w = rng.integers(1, 1000, m)
+    np.testing.assert_array_equal(lt._relax_jax(entry, src, dst, w),
+                                  lt._relax_numpy(entry, src, dst, w))

@@ -9,9 +9,8 @@ import sys
 
 
 def _run(mod, *args, timeout=400):
-    # Pin JAX to the CPU backend explicitly: without JAX_PLATFORMS the
-    # subprocess probes for accelerator plugins on CPU-only CI boxes, which
-    # turns a ~7 s training run into a >400 s timeout.
+    # the drivers run at published widths unless told otherwise: pin the
+    # CPU and pass --reduced so these runs stay tiny
     env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu")}
     return subprocess.run(
@@ -20,9 +19,9 @@ def _run(mod, *args, timeout=400):
 
 
 def test_train_driver_end_to_end(tmp_path):
-    r = _run("repro.launch.train", "--arch", "gemma-2b", "--steps", "20",
-             "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path),
-             "--ckpt-every", "10")
+    r = _run("repro.launch.train", "--arch", "gemma-2b", "--reduced",
+             "--steps", "20", "--batch", "2", "--seq", "32",
+             "--ckpt-dir", str(tmp_path), "--ckpt-every", "10")
     assert r.returncode == 0, r.stdout + r.stderr
     last = json.loads(r.stdout.strip().splitlines()[-1])
     assert last["improved"] is True
@@ -32,22 +31,22 @@ def test_train_driver_end_to_end(tmp_path):
 
 
 def test_train_driver_resume(tmp_path):
-    r1 = _run("repro.launch.train", "--arch", "gemma-2b", "--steps", "10",
-              "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path),
-              "--ckpt-every", "5")
+    r1 = _run("repro.launch.train", "--arch", "gemma-2b", "--reduced",
+              "--steps", "10", "--batch", "2", "--seq", "32",
+              "--ckpt-dir", str(tmp_path), "--ckpt-every", "5")
     assert r1.returncode == 0, r1.stdout + r1.stderr
     # resume for a meaningful number of steps: the driver's exit code
     # asserts the loss improved, and a 3-4 step tail is noise-dominated
-    r2 = _run("repro.launch.train", "--arch", "gemma-2b", "--steps", "24",
-              "--batch", "2", "--seq", "32", "--ckpt-dir", str(tmp_path),
-              "--resume")
+    r2 = _run("repro.launch.train", "--arch", "gemma-2b", "--reduced",
+              "--steps", "24", "--batch", "2", "--seq", "32",
+              "--ckpt-dir", str(tmp_path), "--resume")
     assert r2.returncode == 0, r2.stdout + r2.stderr
     assert "resumed from step 10" in r2.stdout
 
 
 def test_serve_driver_end_to_end():
-    r = _run("repro.launch.serve", "--arch", "gemma-2b", "--batch", "2",
-             "--prompt-len", "16", "--gen", "7")
+    r = _run("repro.launch.serve", "--arch", "gemma-2b", "--reduced",
+             "--batch", "2", "--prompt-len", "16", "--gen", "7")
     assert r.returncode == 0, r.stdout + r.stderr
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["decode_tok_per_s"] > 0

@@ -1,0 +1,71 @@
+"""The Pallas kernels compiled ahead of time for a described TPU v5e, at
+the widths of the models that would run them.  Nothing runs: the TPU
+compiler refuses what interpret mode accepts (tiling, unsupported
+primitives, VMEM limits), and each compile takes about a second.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rg_lru import rg_lru_scan
+from repro.kernels.rwkv6_wkv import wkv6
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.mark.parametrize("name,fn,shapes", [
+    # StarCoder2-7B: 36 query and 4 KV heads of 128
+    ("flash_attention prefill T=2048",
+     lambda q, k, v: flash_attention(q, k, v, causal=True),
+     [((1, 36, 2048, 128), BF16), ((1, 4, 2048, 128), BF16),
+      ((1, 4, 2048, 128), BF16)]),
+    ("flash_attention decode T=1",
+     lambda q, k, v: flash_attention(q, k, v, causal=False, kv_len=1000),
+     [((8, 36, 1, 128), BF16), ((8, 4, 1024, 128), BF16),
+      ((8, 4, 1024, 128), BF16)]),
+    # rwkv6-7b: 64 heads of 64, chunk 32
+    ("wkv6", lambda r, k, v, w, u: wkv6(r, k, v, w, u, chunk=32),
+     [((1, 64, 2048, 64), BF16)] * 3 + [((1, 64, 2048, 64), F32),
+                                        ((64, 64), F32)]),
+    # recurrentgemma-9b: RG-LRU width 4096
+    ("rg_lru_scan", rg_lru_scan,
+     [((2, 2048, 4096), BF16), ((2, 2048, 4096), BF16),
+      ((2, 4096), F32)]),
+])
+def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
+    compiled = _compile(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in compiled.as_text(), name
