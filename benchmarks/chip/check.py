@@ -1,0 +1,106 @@
+"""The comparison that decides ``correct`` for a served model.
+
+Once the window has closed and the program's state is freed, a sample of
+the requests the window served, drawn from the seed with the longest among
+them, is run through the float32 reference (``reference.py``) over each
+prompt with its served tokens.  Two numbers are compared, each over every
+served token of the sample:
+
+- ``logit_rel_rms``: at each served row, the root mean square of the
+  program's logits less the reference's, over the vocabulary, as a share
+  of the root mean square of the reference's; the largest over the rows.
+  It sees the program's arithmetic at every position, whether or not
+  rounding moved the largest logit.
+- ``max_logit_gap``: how far the served token's reference logit lies
+  below the reference's best logit at its row; the largest over the rows.
+  The tokens are greedy, so a token that the program altered after its
+  logits were made lies far below, while one that rounding moved lies
+  within a rounding's width.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Sequence
+
+import numpy as np
+
+from cell import rng
+import reference
+
+
+@dataclass
+class Request:
+    """One served answer: the full token sequence (prompt, then every
+    token fed back), and at each row of ``rows`` the token the program
+    served there and the logits (rows, vocab) it served it from."""
+    tokens: np.ndarray
+    rows: np.ndarray
+    served: np.ndarray
+    logits: np.ndarray
+
+    def __len__(self):
+        return len(self.served)
+
+
+def sample(requests: Sequence[Request], k: int, seed: int) -> List[Request]:
+    """``k`` requests drawn from the seed, always with the one that served
+    the most tokens."""
+    order = rng(seed, "check").permutation(len(requests))
+    longest = max(order, key=lambda i: len(requests[i]))
+    rest = [i for i in order if i != longest]
+    return [requests[i] for i in [longest] + rest[:max(0, k - 1)]]
+
+
+def rel_rms(got: np.ndarray, ref: np.ndarray) -> np.ndarray:
+    """Per row: RMS of ``got - ref`` over RMS of ``ref``."""
+    got, ref = got.astype(np.float64), ref.astype(np.float64)
+    return np.sqrt(np.mean((got - ref) ** 2, axis=-1) /
+                   np.mean(ref ** 2, axis=-1))
+
+
+def token_gaps(ref: np.ndarray, tokens: np.ndarray) -> np.ndarray:
+    """Per row: the best reference logit less that of ``tokens``."""
+    return ref.max(axis=-1) - np.take_along_axis(
+        ref, tokens[:, None].astype(np.int64), axis=-1)[:, 0]
+
+
+def numbers(ref: Sequence[np.ndarray], logits: Sequence[np.ndarray],
+            served: Sequence[np.ndarray]) -> Dict[str, float]:
+    """The compared numbers of logits and served tokens against the
+    reference's logits, request by request."""
+    return {"logit_rel_rms": float(max(rel_rms(g, r).max()
+                                       for g, r in zip(logits, ref))),
+            "max_logit_gap": float(max(token_gaps(r, t).max()
+                                       for r, t in zip(ref, served)))}
+
+
+def reference_logits(s: dict, key, requests: Sequence[Request],
+                     quant=None) -> List[np.ndarray]:
+    return reference.row_logits(s, key, [(r.tokens, r.rows)
+                                         for r in requests], quant=quant)
+
+
+def compare(s: dict, key, requests: Sequence[Request]) -> Dict[str, float]:
+    """The program's compared numbers."""
+    ref = reference_logits(s, key, requests)
+    return numbers(ref, [r.logits for r in requests],
+                   [r.served for r in requests])
+
+
+def judge(compared: dict, limits: dict) -> bool:
+    """Every compared number finite and at or under its limit."""
+    return all(name in compared and np.isfinite(compared[name]) and
+               compared[name] <= limits[name] for name in limits)
+
+
+def control(s: dict, key, requests: Sequence[Request],
+            ref: Optional[List[np.ndarray]] = None) -> Dict[str, float]:
+    """The same numbers for the control: the reference with float8
+    operands (``reference.py``) put in the program's place, its logits and
+    the tokens it puts first at the same rows of the same sequences.
+    ``ref``: the reference's logits of ``requests``, where already made."""
+    if ref is None:
+        ref = reference_logits(s, key, requests)
+    low = reference_logits(s, key, requests, quant="fp8")
+    return numbers(ref, low, [g.argmax(axis=-1) for g in low])
