@@ -6,6 +6,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import os
+import re
 from pathlib import Path
 from typing import Optional
 
@@ -21,7 +22,18 @@ def init_compile_cache() -> str:
 
     ``JAX_COMPILATION_CACHE_DIR``, when set, is left to JAX.  Otherwise the
     cache lives at ``<checkout>/.jax_cache``: a fixed path, because a
-    directory that moves between runs never hits."""
+    directory that moves between runs never hits.
+
+    The cache key includes the programs' metadata.  Without it, two
+    programs that differ only in their named scopes share a key, and the
+    one compiled second is handed the first's executable, whose HLO
+    carries the first's op_names: a device trace read through the scopes
+    (``models/layers.py:SCOPES``) would then read the wrong program.  The
+    metadata's source paths are made relative to the checkout, so that
+    the same program in another checkout still hits."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(f"{CHECKOUT}{os.sep}"))
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -4,14 +4,20 @@
       --layers 8 --batch 8 --prompt-len 512 --gen 32
   PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve \
       --arch gemma-2b --reduced --batch 4 --prompt-len 32 --gen 16
+
+``--profile DIR`` traces the timed prefill and decode steps with the JAX
+profiler into ``DIR``; each step is a ``StepTraceAnnotation`` (``prefill``,
+``decode``) there.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import statistics
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,10 +25,12 @@ import numpy as np
 
 from ..configs.base import ArchConfig, ShapeConfig
 from ..models import api
+from ..models.layers import scope
 from ..train.step import make_prefill_step, make_serve_step
 from .common import add_model_args, init_compile_cache, model_config
 
 
+@scope("sample")
 def _greedy(logits):
     return jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
 
@@ -47,13 +55,14 @@ def build(cfg: ArchConfig, max_len: int):
 
 
 def generate(cfg: ArchConfig, params, batch, *, prompt_len: int, gen: int,
-             max_len: int) -> dict:
+             max_len: int, profile: Optional[str] = None) -> dict:
     """Greedy generation of ``gen`` tokens after the prompt ``batch``.
 
     Both programs are compiled before anything is timed; the prefill and
     every decode step end in ``block_until_ready``.  ``tokens`` holds the
     prefill's token and the ``gen`` decoded ones; ``first_decode_logits``
-    are the logits of the first decode step (position ``prompt_len``)."""
+    are the logits of the first decode step (position ``prompt_len``).
+    With ``profile``, the timed steps are traced into that directory."""
     if prompt_len + gen > max_len:
         raise ValueError(f"prompt {prompt_len} + gen {gen} > max_len "
                          f"{max_len}")
@@ -66,20 +75,24 @@ def generate(cfg: ArchConfig, params, batch, *, prompt_len: int, gen: int,
                           jax.ShapeDtypeStruct((), jnp.int32)).compile()
     compile_s = {"prefill": t1 - t0, "decode": time.perf_counter() - t1}
 
-    t0 = time.perf_counter()
-    cache, _, tok = jax.block_until_ready(prefill(params, batch))
-    prefill_s = time.perf_counter() - t0
-
-    toks, step_s, first_logits = [tok], [], None
-    for i in range(gen):
+    with (jax.profiler.trace(profile) if profile
+          else contextlib.nullcontext()):
         t0 = time.perf_counter()
-        logits, cache, tok = jax.block_until_ready(
-            decode(params, cache, tok, jnp.asarray(prompt_len + i,
-                                                    jnp.int32)))
-        step_s.append(time.perf_counter() - t0)
-        toks.append(tok)
-        if first_logits is None:
-            first_logits = logits
+        with jax.profiler.StepTraceAnnotation("prefill", step_num=0):
+            cache, _, tok = jax.block_until_ready(prefill(params, batch))
+        prefill_s = time.perf_counter() - t0
+
+        toks, step_s, first_logits = [tok], [], None
+        for i in range(gen):
+            t0 = time.perf_counter()
+            with jax.profiler.StepTraceAnnotation("decode", step_num=i):
+                logits, cache, tok = jax.block_until_ready(
+                    decode(params, cache, tok,
+                           jnp.asarray(prompt_len + i, jnp.int32)))
+            step_s.append(time.perf_counter() - t0)
+            toks.append(tok)
+            if first_logits is None:
+                first_logits = logits
     return {"tokens": np.concatenate([np.asarray(t) for t in toks], axis=1),
             "first_decode_logits": np.asarray(first_logits, np.float32),
             "compile_s": compile_s, "prefill_s": prefill_s,
@@ -92,6 +105,8 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="trace the timed steps into DIR")
     args = ap.parse_args(argv)
 
     init_compile_cache()
@@ -102,7 +117,8 @@ def main(argv=None):
     batch = {k: v for k, v in api.make_batch(cfg, shape).items()
              if k != "labels"}
     out = generate(cfg, params, batch, prompt_len=args.prompt_len,
-                   gen=args.gen, max_len=args.prompt_len + args.gen + 8)
+                   gen=args.gen, max_len=args.prompt_len + args.gen + 8,
+                   profile=args.profile)
     med = statistics.median(out["decode_step_s"])
     print(json.dumps({
         "arch": cfg.name, "layers": cfg.n_layers, "batch": args.batch,

@@ -11,15 +11,20 @@ gradient compression.
   PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.train \
       --arch llama3-8b --reduced --steps 200 --batch 8 --seq 128 \
       --ckpt-dir ckpt
+
+``--profile DIR`` traces the timed steps with the JAX profiler into
+``DIR``; each step is a ``StepTraceAnnotation`` (``train``) there.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import statistics
 import time
 from functools import partial
+from typing import Optional
 
 import jax
 
@@ -59,12 +64,14 @@ def build(cfg: ArchConfig, mesh, shape: ShapeConfig, *, lr: float,
 
 def train(cfg: ArchConfig, mesh, shape: ShapeConfig, *, steps: int,
           lr: float, compress_grads: bool = False, ckpt=None,
-          ckpt_every: int = 25, resume: bool = False, log_every: int = 10):
+          ckpt_every: int = 25, resume: bool = False, log_every: int = 10,
+          profile: Optional[str] = None):
     """Train for ``steps`` steps (counting from a resumed checkpoint).
 
     The step is compiled before the first one runs, so ``compile_s`` and
     the per-step times (``step_s``, each ending in ``block_until_ready``)
-    stay apart.  Returns ``(state, summary)``."""
+    stay apart.  With ``profile``, the steps are traced into that
+    directory.  Returns ``(state, summary)``."""
     with hints.use_mesh(mesh):
         state, step_fn, st_sh = build(cfg, mesh, shape, lr=lr,
                                       compress_grads=compress_grads)
@@ -80,21 +87,25 @@ def train(cfg: ArchConfig, mesh, shape: ShapeConfig, *, steps: int,
         pipe.start(from_step=start)
         losses, step_s = [], []
         try:
-            for step in range(start, steps):
-                batch = pipe.get()
-                t0 = time.perf_counter()
-                state, metrics = step_fn(state, batch)
-                jax.block_until_ready((state, metrics))
-                step_s.append(time.perf_counter() - t0)
-                losses.append(metrics["loss"])
-                if log_every and (step % log_every == 0 or
-                                  step == steps - 1):
-                    print(json.dumps({
-                        "step": step, "loss": round(float(losses[-1]), 4),
-                        "grad_norm": round(float(metrics["grad_norm"]), 3),
-                        "step_s": step_s[-1]}))
-                if ckpt and (step + 1) % ckpt_every == 0:
-                    ckpt.save(step + 1, state)
+            with (jax.profiler.trace(profile) if profile
+                  else contextlib.nullcontext()):
+                for step in range(start, steps):
+                    batch = pipe.get()
+                    t0 = time.perf_counter()
+                    with jax.profiler.StepTraceAnnotation("train",
+                                                          step_num=step):
+                        state, metrics = step_fn(state, batch)
+                        jax.block_until_ready((state, metrics))
+                    step_s.append(time.perf_counter() - t0)
+                    losses.append(metrics["loss"])
+                    if log_every and (step % log_every == 0 or
+                                      step == steps - 1):
+                        print(json.dumps({
+                            "step": step, "loss": round(float(losses[-1]), 4),
+                            "grad_norm": round(float(metrics["grad_norm"]), 3),
+                            "step_s": step_s[-1]}))
+                    if ckpt and (step + 1) % ckpt_every == 0:
+                        ckpt.save(step + 1, state)
         finally:
             pipe.stop()
     losses = [float(x) for x in losses]
@@ -118,6 +129,8 @@ def main(argv=None):
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="trace the timed steps into DIR")
     args = ap.parse_args(argv)
 
     init_compile_cache()
@@ -128,7 +141,7 @@ def main(argv=None):
     state, out = train(cfg, mesh, shape, steps=args.steps, lr=args.lr,
                        compress_grads=args.compress_grads, ckpt=ckpt,
                        ckpt_every=args.ckpt_every, resume=args.resume,
-                       log_every=args.log_every)
+                       log_every=args.log_every, profile=args.profile)
     if ckpt:
         ckpt.save(args.steps, state)
         ckpt.wait()

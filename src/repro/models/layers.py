@@ -18,6 +18,31 @@ from ..distributed import hints
 
 Params = Dict[str, jnp.ndarray]
 
+# Named scopes of the decoder's parts.  ``jax.named_scope`` puts each into
+# the op_name of every HLO instruction built inside it, so a device trace
+# of the compiled program can be read part by part.  What lies under none
+# of them is the work the programs do between the parts: the layer scan's
+# slicing and stacking of the stacked cache, the prefill's cache padding.
+SCOPES = (
+    "embed",             # token embedding lookup
+    "norm",              # RMSNorm
+    "qkv_proj",          # Q, K, V projections, RoPE included
+    "attention_kernel",  # softmax(QK^T)V: the part a flash kernel replaces
+    "attn_out",          # output projection and its residual add
+    "kv_cache_write",    # the decode step's write of the new K and V
+    "mlp",               # dense or expert MLP and its residual add
+    "lm_head",           # logits
+    "sample",            # greedy choice of the next token
+)
+
+
+def scope(name: str):
+    """``jax.named_scope`` for one of ``SCOPES``; a context manager, or a
+    decorator that opens the scope around each call."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not one of {SCOPES}")
+    return jax.named_scope(name)
+
 
 # ---------------------------------------------------------------------------
 # initializers
@@ -36,6 +61,7 @@ def embed_init(key, vocab: int, d: int, dtype) -> jnp.ndarray:
 # norms / activations
 # ---------------------------------------------------------------------------
 
+@scope("norm")
 def rmsnorm(x: jnp.ndarray, g: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     dt = x.dtype
     x32 = x.astype(jnp.float32)
@@ -43,6 +69,7 @@ def rmsnorm(x: jnp.ndarray, g: jnp.ndarray, eps: float = 1e-5) -> jnp.ndarray:
     return (n * (1.0 + g.astype(jnp.float32))).astype(dt)
 
 
+@scope("mlp")
 def glu_mlp(x: jnp.ndarray, p: Params, act: str) -> jnp.ndarray:
     """SwiGLU / GeGLU: (act(x W_g) * (x W_u)) W_d — or, when the params
     carry no gate matrix ("gelu" archs like StarCoder2), a plain 2-matrix
@@ -116,28 +143,8 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # sharding would all-gather S x Kh x Dh every step (measured: 64 GB
     # per decode step on llama3-8b/decode_32k before this branch existed)
     if T <= 16 and S >= 4096:
-        q = hints.constrain(q, "dp", None, None, None)
-        k = hints.constrain(k, "dp", "spm", None, None)
-        v = hints.constrain(v, "dp", "spm", None, None)
-        scale = 1.0 / math.sqrt(Dh)
-        qs = (q * scale).reshape(B, T, Kh, G, Dh)
-        s = jnp.einsum("btkgd,bskd->bkgts", qs, k,
-                       preferred_element_type=jnp.float32)
-        pos_k = jnp.arange(S)
-        q_pos = q_offset + jnp.arange(T)
-        mask = jnp.ones((T, S), dtype=bool)
-        if causal:
-            mask = mask & (pos_k[None, :] <= q_pos[:, None])
-        if window:
-            mask = mask & (pos_k[None, :] > q_pos[:, None] - window)
-        if kv_len is not None:
-            mask = mask & (pos_k[None, :] < kv_len)
-        s = jnp.where(mask[None, None, None], s, NEG_INF)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum("bkgts,bskd->btkgd", (p / l).astype(v.dtype), v)
-        return o.reshape(B, T, H, Dh)
+        return _decode_attention(q, k, v, causal=causal, q_offset=q_offset,
+                                 window=window, kv_len=kv_len)
     # sharding: heads over 'model' when divisible (Megatron attention);
     # otherwise fall back to sequence parallelism — shard the query rows
     # and let K/V be gathered per layer (cheap relative to replicating
@@ -169,13 +176,40 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                             head_sharded=head_sharded)
 
 
-def _attention_inner(*args, **kw):
-    with jax.named_scope("attention_kernel"):
-        return _attention_inner_impl(*args, **kw)
+@scope("attention_kernel")
+def _decode_attention(q, k, v, *, causal, q_offset, window, kv_len):
+    """A few queries against a long cache, in one pass over every cached
+    position."""
+    B, T, H, Dh = q.shape
+    S, Kh = k.shape[1], k.shape[2]
+    G = H // Kh
+    q = hints.constrain(q, "dp", None, None, None)
+    k = hints.constrain(k, "dp", "spm", None, None)
+    v = hints.constrain(v, "dp", "spm", None, None)
+    scale = 1.0 / math.sqrt(Dh)
+    qs = (q * scale).reshape(B, T, Kh, G, Dh)
+    s = jnp.einsum("btkgd,bskd->bkgts", qs, k,
+                   preferred_element_type=jnp.float32)
+    pos_k = jnp.arange(S)
+    q_pos = q_offset + jnp.arange(T)
+    mask = jnp.ones((T, S), dtype=bool)
+    if causal:
+        mask = mask & (pos_k[None, :] <= q_pos[:, None])
+    if window:
+        mask = mask & (pos_k[None, :] > q_pos[:, None] - window)
+    if kv_len is not None:
+        mask = mask & (pos_k[None, :] < kv_len)
+    s = jnp.where(mask[None, None, None], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("bkgts,bskd->btkgd", (p / l).astype(v.dtype), v)
+    return o.reshape(B, T, H, Dh)
 
 
-def _attention_inner_impl(q, k, v, *, causal, q_offset, window, kv_len,
-                          block, head_sharded):
+@scope("attention_kernel")
+def _attention_inner(q, k, v, *, causal, q_offset, window, kv_len, block,
+                     head_sharded):
     """The part the Pallas flash kernel replaces on TPU — wrapped in a
     named scope so the HLO analyzer can attribute its traffic."""
     B, T, H, Dh = q.shape
@@ -253,6 +287,7 @@ def gqa_init(key, d: int, n_heads: int, n_kv: int, hd: int, dtype) -> Params:
             "wo": dense_init(k4, n_heads * hd, d, dtype)}
 
 
+@scope("qkv_proj")
 def gqa_project(x: jnp.ndarray, p: Params, n_heads: int, n_kv: int, hd: int,
                 positions, theta: float, use_rope: bool = True
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -285,6 +320,7 @@ def moe_init(key, d: int, num_experts: int, d_ff: int, dtype) -> Params:
     }
 
 
+@scope("mlp")
 def moe_mlp(x: jnp.ndarray, p: Params, top_k: int, capacity_factor: float,
             act: str = "swiglu", group_size: int = 512,
             expert_sharding: str = "tp") -> Tuple[jnp.ndarray, jnp.ndarray]:

@@ -93,7 +93,7 @@ def layer_fwd(p: Params, x: jnp.ndarray, cfg: ArchConfig,
         k_all, v_all = k, v
     o = L.attention(q, k_all, v_all, causal=causal, q_offset=q_offset,
                     window=cfg.window, kv_len=kv_len)
-    x = x + o.reshape(*o.shape[:2], -1) @ p["attn"]["wo"]
+    x = _attn_out(x, o, p["attn"]["wo"])
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe:
@@ -104,13 +104,26 @@ def layer_fwd(p: Params, x: jnp.ndarray, cfg: ArchConfig,
                            expert_sharding=cfg.moe.sharding)
     else:
         m = L.glu_mlp(h2, p["mlp"], cfg.act)
-    return x + m, (k, v), aux
+    return _residual_mlp(x, m), (k, v), aux
+
+
+# The residual adds sit in the scope of the part they close: XLA fuses each
+# into the matmul that feeds it, and a fused op is read by its root's scope.
+@L.scope("attn_out")
+def _attn_out(x, o, wo):
+    return x + o.reshape(*o.shape[:2], -1) @ wo
+
+
+@L.scope("mlp")
+def _residual_mlp(x, m):
+    return x + m
 
 
 # ---------------------------------------------------------------------------
 # full forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+@L.scope("embed")
 def embed_inputs(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
                  patches: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     x = params["embed"][tokens]
@@ -162,7 +175,8 @@ def prefill(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
     """Run the prompt, return (cache, last-token logits)."""
     x, kv, _ = forward(params, cfg, tokens, patches, collect_cache=True,
                        remat=False)
-    logits = x[:, -1:] @ lm_head(params, cfg)
+    with L.scope("lm_head"):
+        logits = x[:, -1:] @ lm_head(params, cfg)
     return {"k": kv[0], "v": kv[1]}, logits
 
 
@@ -173,9 +187,7 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
     token: (B, 1) int32; pos: scalar int32 — current length (same for the
     batch; per-request lengths are handled by the serving layer's bucketing).
     """
-    x = params["embed"][token]
-    if cfg.family == "dense" and cfg.tie_embeddings:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    x = embed_inputs(params, cfg, token)
     positions = pos + jnp.arange(1)
 
     def body(x, layer_in):
@@ -185,13 +197,14 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
         h = L.rmsnorm(x, pl["ln1"], cfg.norm_eps)
         q, k, v = L.gqa_project(h, pl["attn"], cfg.n_heads, cfg.n_kv_heads,
                                 cfg.hd, positions, cfg.rope_theta)
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype), pos,
-                                                 axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype), pos,
-                                                 axis=1)
+        with L.scope("kv_cache_write"):
+            kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype),
+                                                     pos, axis=1)
+            vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype),
+                                                     pos, axis=1)
         o = L.attention(q, kc, vc, causal=False, q_offset=pos,
                         window=cfg.window, kv_len=pos + 1)
-        x = x + o.reshape(*o.shape[:2], -1) @ pl["attn"]["wo"]
+        x = _attn_out(x, o, pl["attn"]["wo"])
         h2 = L.rmsnorm(x, pl["ln2"], cfg.norm_eps)
         if cfg.moe:
             m, _ = L.moe_mlp(h2, pl["moe"], cfg.moe.top_k,
@@ -200,10 +213,11 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
                              expert_sharding=cfg.moe.sharding)
         else:
             m = L.glu_mlp(h2, pl["mlp"], cfg.act)
-        return x + m, (kc, vc)
+        return _residual_mlp(x, m), (kc, vc)
 
     x, (k_new, v_new) = jax.lax.scan(
         body, x, (params["layers"], cache["k"], cache["v"]))
     x = L.rmsnorm(x, params["norm_f"], cfg.norm_eps)
-    logits = x @ lm_head(params, cfg)
+    with L.scope("lm_head"):
+        logits = x @ lm_head(params, cfg)
     return logits, {"k": k_new, "v": v_new}
