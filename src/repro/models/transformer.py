@@ -186,22 +186,29 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
 
     token: (B, 1) int32; pos: scalar int32 — current length (same for the
     batch; per-request lengths are handled by the serving layer's bucketing).
+    The layer scan returns only the step's new K/V per layer; the cache is
+    written once, in place when donated, at ``pos`` after the loop.
     """
     x = embed_inputs(params, cfg, token)
     positions = pos + jnp.arange(1)
 
-    def body(x, layer_in):
-        pl, kc, vc = layer_in
+    def body(carry, pl):
+        x, i = carry
+        # fetching the layer's K/V from the stacked cache is the attention's
+        # traffic: the compiler stages it into on-chip memory for the dots
+        with L.scope("attention_kernel"):
+            kc, vc = (jax.lax.dynamic_index_in_dim(cache[name], i,
+                                                   keepdims=False)
+                      for name in ("k", "v"))
         kc = hints.constrain(kc, "dp", "model", None, None)
         vc = hints.constrain(vc, "dp", "model", None, None)
         h = L.rmsnorm(x, pl["ln1"], cfg.norm_eps)
         q, k, v = L.gqa_project(h, pl["attn"], cfg.n_heads, cfg.n_kv_heads,
                                 cfg.hd, positions, cfg.rope_theta)
+        k, v = k.astype(kc.dtype), v.astype(vc.dtype)
         with L.scope("kv_cache_write"):
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, k.astype(kc.dtype),
-                                                     pos, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, v.astype(vc.dtype),
-                                                     pos, axis=1)
+            kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=1)
+            vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=1)
         o = L.attention(q, kc, vc, causal=False, q_offset=pos,
                         window=cfg.window, kv_len=pos + 1)
         x = _attn_out(x, o, pl["attn"]["wo"])
@@ -213,11 +220,17 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
                              expert_sharding=cfg.moe.sharding)
         else:
             m = L.glu_mlp(h2, pl["mlp"], cfg.act)
-        return _residual_mlp(x, m), (kc, vc)
+        return (_residual_mlp(x, m), i + 1), (k, v)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
+    # (L, B, 1, Kh, Dh) as ys: stacking each layer's whole updated cache
+    # would copy all of it every step
+    (x, _), (k_new, v_new) = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.int32)), params["layers"])
+    with L.scope("kv_cache_write"):
+        cache = {name: jax.lax.dynamic_update_slice_in_dim(cache[name], new,
+                                                           pos, axis=2)
+                 for name, new in (("k", k_new), ("v", v_new))}
     x = L.rmsnorm(x, params["norm_f"], cfg.norm_eps)
     with L.scope("lm_head"):
         logits = x @ lm_head(params, cfg)
-    return logits, {"k": k_new, "v": v_new}
+    return logits, cache
