@@ -1,16 +1,22 @@
 """One cell of the benchmark: its entry in ``BENCHMARK.json`` and the files
 that entry names, found by name under this directory:
 
-- ``configs/<config>.json``: the model's sizes as the cell runs them;
+- ``configs/<config>.json``: the model's sizes as the cell runs them, and
+  its family;
+- ``families/<family>.py``: what belongs to the model's architecture (see
+  ``families/__init__.py``);
 - ``traffic/<traffic>.json``: the parameters of the traffic mix;
 - ``cells/<cell>.json``: how many requests the check samples and the
   limits of the numbers that decide ``correct``.
 
-A new cell adds files and entries; nothing here names a cell.
+A new cell adds files and entries; nothing here names a cell or a
+family.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
@@ -20,11 +26,20 @@ import numpy as np
 
 HERE = Path(__file__).resolve().parent
 REPO = HERE.parents[1]
+FAMILIES = HERE / "families"
 
 
 def read_json(path: Path) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        path.stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclass(frozen=True)
@@ -59,12 +74,14 @@ def load(workload: str, bench: Optional[dict] = None) -> Cell:
         raise SystemExit(f"unknown workload {workload!r}; BENCHMARK.json "
                          f"has {[w['name'] for w in bench['workloads']]}")
     config = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    config = read_json(REPO / config["file"])
+    family(config["family"])        # stops before set-up where it has no file
     e2e = tuple(m for m in bench["end_to_end"] if reports(m, workload, ()))
     names = {m["name"] for m in e2e}
     per_layer = tuple(m for m in bench["per_layer"]
                       if reports(m, workload, names))
     return Cell(name=workload, chips=int(entry["chips"]),
-                config=read_json(REPO / config["file"]),
+                config=config,
                 traffic=read_json(HERE / "traffic" /
                                   f"{entry['traffic']}.json"),
                 spec=read_json(HERE / "cells" / f"{workload}.json"),
@@ -91,36 +108,29 @@ def prng_key(seed: int):
 
 
 # ---------------------------------------------------------------------------
-# the model as the program is given it
+# the model's family: everything that depends on its architecture
 # ---------------------------------------------------------------------------
 
-ACTIVATIONS = {"gelu_pytorch_tanh": "gelu", "silu": "swiglu"}
+def family(name: str):
+    """The module of the family ``name``, ``families/<name>.py`` (loaded
+    once); stops when there is no such file."""
+    return _family_module(FAMILIES, name)
+
+
+@functools.cache
+def _family_module(families: Path, name: str):
+    path = families / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"model family {name!r}: no file {path}")
+    return load_module(path)
 
 
 def sizes(config: dict) -> dict:
-    """The configuration's sizes under plain names; ``window`` is the
-    sliding window (0: none)."""
-    eps = config.get("rms_norm_eps", config.get("norm_epsilon"))
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    return {"layers": int(config["num_hidden_layers"]), "d_model": d,
-            "heads": h,
-            "kv_heads": config["num_key_value_heads"],
-            "head_dim": config.get("head_dim", d // h),
-            "d_ff": config["intermediate_size"],
-            "vocab": config["vocab_size"],
-            "act": ACTIVATIONS[config["hidden_act"]],
-            "rope_theta": float(config["rope_theta"]), "eps": float(eps),
-            "window": int(config.get("sliding_window") or 0),
-            "dtype": config["torch_dtype"]}
+    """The configuration's sizes, as its family names them."""
+    return family(config["family"]).sizes(config)
 
 
 def arch_config(cell: Cell):
     """The program's ``ArchConfig`` for this cell's model and depth."""
-    from repro.configs.base import ArchConfig
-    s = sizes(cell.config)
-    return ArchConfig(
-        name=cell.config["name"], family="dense", n_layers=s["layers"],
-        d_model=s["d_model"], n_heads=s["heads"], n_kv_heads=s["kv_heads"],
-        d_ff=s["d_ff"], vocab=s["vocab"], head_dim=s["head_dim"],
-        act=s["act"], norm_eps=s["eps"], rope_theta=s["rope_theta"],
-        window=s["window"], dtype=s["dtype"])
+    return family(cell.config["family"]).arch_config(cell.config,
+                                                     cell.config["name"])

@@ -2,9 +2,10 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests the window served, drawn from the seed with the longest among
-them, is run through the float32 reference (``reference.py``) over each
-prompt with its served tokens.  Two numbers are compared, each over every
-served token of the sample:
+them, is run through the float32 reference of the model's family
+(``families/<family>.py``: ``row_logits``) over each prompt with its
+served tokens.  Two numbers are compared, each over every served token of
+the sample:
 
 - ``logit_rel_rms``: at each served row, the root mean square of the
   program's logits less the reference's, over the vocabulary, as a share
@@ -25,8 +26,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from cell import rng
-import reference
+from cell import family, rng
 
 
 @dataclass
@@ -77,8 +77,8 @@ def numbers(ref: Sequence[np.ndarray], logits: Sequence[np.ndarray],
 
 def reference_logits(s: dict, key, requests: Sequence[Request],
                      quant=None) -> List[np.ndarray]:
-    return reference.row_logits(s, key, [(r.tokens, r.rows)
-                                         for r in requests], quant=quant)
+    return family(s["family"]).row_logits(
+        s, key, [(r.tokens, r.rows) for r in requests], quant=quant)
 
 
 def compare(s: dict, key, requests: Sequence[Request]) -> Dict[str, float]:
@@ -97,8 +97,9 @@ def judge(compared: dict, limits: dict) -> bool:
 def control(s: dict, key, requests: Sequence[Request],
             ref: Optional[List[np.ndarray]] = None) -> Dict[str, float]:
     """The same numbers for the control: the reference with float8
-    operands (``reference.py``) put in the program's place, its logits and
-    the tokens it puts first at the same rows of the same sequences.
+    operands (``row_logits(..., quant="fp8")``) put in the program's
+    place, its logits and the tokens it puts first at the same rows of the
+    same sequences.
     ``ref``: the reference's logits of ``requests``, where already made."""
     if ref is None:
         ref = reference_logits(s, key, requests)

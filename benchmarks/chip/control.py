@@ -11,8 +11,8 @@ the numbers ``check.py`` compares for the program (``program``), for the
 control (``control``: the reference computed with float8 operands put in
 the program's place) and, for ``max_logit_gap``, for each served token
 altered to the next id (``altered``).  The benchmark's runs never run
-this.  With ``--witness`` it also compares the program's first-layer
-weights with the ones the reference makes from the same seed.
+this.  With ``--witness`` it also compares the program's weights with the
+ones the reference makes from the same seed (the family's ``witness``).
 """
 
 from __future__ import annotations
@@ -29,24 +29,7 @@ sys.path.insert(1, str(HERE.parents[1] / "src"))
 
 import cell as cells  # noqa: E402
 import check  # noqa: E402
-import reference  # noqa: E402
 import run as bench  # noqa: E402
-
-
-def witness(run, s, key) -> float:
-    """Largest difference between the program's layer-0 weights and the
-    reference's, both widened to float32 (0 when the recipe agrees)."""
-    import numpy as np
-    _, layer_keys, _ = reference.model_keys(key, s["layers"])
-    ref = reference.layer_weights(layer_keys[0], s)
-    prog = run.params["layers"]
-    worst = 0.0
-    for group in ("attn", "mlp"):
-        for name, leaf in prog[group].items():
-            got = np.asarray(leaf[0]).astype(np.float32)
-            worst = max(worst, float(np.abs(got - np.asarray(ref[name]))
-                                     .max()))
-    return worst
 
 
 def readings(s: dict, key, reqs) -> dict:
@@ -87,7 +70,8 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         key = cells.prng_key(seed)
         run = kind.Run(cell, seed)
-        w = witness(run, s, key) if args.witness else None
+        w = (cells.family(s["family"]).witness(run.params, key, s)
+             if args.witness else None)
         out = run.window(args.seconds)
         run.release()
         reqs = check.sample(run.requests(), cell.spec["check"]["requests"],

@@ -1,7 +1,11 @@
-"""Plain float32 reference of the dense GQA decoder that both
-configurations are (StarCoder2, Phi-3), written from the published
-descriptions in straightforward ``jax.numpy``: no kernel, no cache, no
-batching, no blockwise attention.  It imports nothing of the program.
+"""The dense GQA decoder (StarCoder2, Phi-3): its sizes, the program's
+``ArchConfig`` for it, its plain float32 reference, the check of the
+program's weight recipe, and the work of its prefill and decode step.
+
+The reference is written from the published descriptions in
+straightforward ``jax.numpy``: no kernel, no cache, no batching, no
+blockwise attention.  It imports nothing of the program; only
+``arch_config`` does, to hand the program its model.
 
 Layer: ``x += Wo . attn(rope(Wq n1(x)), rope(Wk n1(x)), Wv n1(x))``, then
 ``x += mlp(n2(x))`` with ``mlp(h) = Wd gelu_tanh(Wu h)`` (StarCoder2) or
@@ -33,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from work import BF16, pairs
+
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
@@ -50,6 +56,44 @@ def _q(x, quant):
 
 def _mm(a, b, quant):
     return jnp.matmul(_q(a, quant), _q(b, quant), precision=HIGHEST)
+
+
+# ---------------------------------------------------------------------------
+# the configuration's sizes, and the model as the program is given it
+# ---------------------------------------------------------------------------
+
+ACTIVATIONS = {"gelu_pytorch_tanh": "gelu", "silu": "swiglu"}
+
+
+def sizes(config: dict) -> dict:
+    """The configuration's sizes under plain names; ``window`` is the
+    sliding window (0: none)."""
+    eps = config.get("rms_norm_eps", config.get("norm_epsilon"))
+    d, h = config["hidden_size"], config["num_attention_heads"]
+    return {"family": config["family"],
+            "layers": int(config["num_hidden_layers"]), "d_model": d,
+            "heads": h,
+            "kv_heads": config["num_key_value_heads"],
+            "head_dim": config.get("head_dim", d // h),
+            "d_ff": config["intermediate_size"],
+            "vocab": config["vocab_size"],
+            "act": ACTIVATIONS[config["hidden_act"]],
+            "rope_theta": float(config["rope_theta"]), "eps": float(eps),
+            "window": int(config.get("sliding_window") or 0),
+            "dtype": config["torch_dtype"]}
+
+
+def arch_config(config: dict, name: str):
+    """The program's ``ArchConfig`` for this model at the configuration's
+    depth."""
+    from repro.configs.base import ArchConfig
+    s = sizes(config)
+    return ArchConfig(
+        name=name, family="dense", n_layers=s["layers"],
+        d_model=s["d_model"], n_heads=s["heads"], n_kv_heads=s["kv_heads"],
+        d_ff=s["d_ff"], vocab=s["vocab"], head_dim=s["head_dim"],
+        act=s["act"], norm_eps=s["eps"], rope_theta=s["rope_theta"],
+        window=s["window"], dtype=s["dtype"])
 
 
 # ---------------------------------------------------------------------------
@@ -222,3 +266,85 @@ def row_logits(s: dict, key, seqs, quant=None):
         return [np.asarray(head(w, x, jnp.asarray(rows, jnp.int32), frozen,
                                 quant))
                 for x, (_, rows) in zip(xs, seqs)]
+
+
+# ---------------------------------------------------------------------------
+# the program's weight recipe
+# ---------------------------------------------------------------------------
+
+def witness(params, key, s: dict) -> float:
+    """Largest difference between the program's layer-0 weights
+    (``params``) and the reference's, both widened to float32 (0 when the
+    recipe agrees)."""
+    _, layer_keys, _ = model_keys(key, s["layers"])
+    ref = layer_weights(layer_keys[0], s)
+    prog = params["layers"]
+    worst = 0.0
+    for group in ("attn", "mlp"):
+        for name, leaf in prog[group].items():
+            got = np.asarray(leaf[0]).astype(np.float32)
+            worst = max(worst, float(np.abs(got - np.asarray(ref[name]))
+                                     .max()))
+    return worst
+
+
+# ---------------------------------------------------------------------------
+# work, from the shapes (see ``work.py`` for what is counted)
+# ---------------------------------------------------------------------------
+
+def layer_params(s) -> int:
+    """Matrix parameters of one layer (the norms are negligible)."""
+    d, hd = s["d_model"], s["head_dim"]
+    attn = 2 * d * s["heads"] * hd + 2 * d * s["kv_heads"] * hd
+    mlp = (2 if s["act"] == "gelu" else 3) * d * s["d_ff"]
+    return attn + mlp
+
+
+def head_params(s) -> int:
+    return s["d_model"] * s["vocab"]
+
+
+def attention_flops(s, batch: int, queries: int, keys_before: int = 0
+                    ) -> float:
+    """QK^T and PV over every layer for the ``pairs`` of ``queries`` new
+    positions."""
+    return 4.0 * s["layers"] * batch * s["heads"] * s["head_dim"] * \
+        pairs(queries, keys_before, s["window"])
+
+
+def attention_bytes(s, batch: int, queries: int, keys: int) -> float:
+    """Q and O of the new positions, K and V of every key, per layer."""
+    q = batch * queries * s["heads"] * s["head_dim"] * BF16
+    kv = batch * keys * s["kv_heads"] * s["head_dim"] * BF16
+    return float(s["layers"] * (2 * q + 2 * kv))
+
+
+def prefill(s, batch: int, prompt: int) -> dict:
+    """One prefill call: every layer over every prompt position, the LM
+    head over the last position only (what the program returns)."""
+    dense = 2.0 * batch * prompt * s["layers"] * layer_params(s) + \
+        2.0 * batch * head_params(s)
+    att = attention_flops(s, batch, prompt)
+    weights = (s["layers"] * layer_params(s) + head_params(s)) * BF16
+    kv_out = 2 * s["layers"] * batch * prompt * s["kv_heads"] * \
+        s["head_dim"] * BF16
+    return {"flops": dense + att, "bytes": float(weights + kv_out),
+            "attention_flops": att,
+            "attention_bytes": attention_bytes(s, batch, prompt, prompt)}
+
+
+def decode_step(s, batch: int, pos: int) -> dict:
+    """One decode step at position ``pos``: one token per request that
+    attends to its live keys, ``pos + 1`` or the window."""
+    live = pairs(1, pos, s["window"])
+    dense = 2.0 * batch * (s["layers"] * layer_params(s) + head_params(s))
+    att = attention_flops(s, batch, 1, pos)
+    weights = (s["layers"] * layer_params(s) + head_params(s)) * BF16
+    embed_rows = batch * s["d_model"] * BF16
+    kv_read = 2 * s["layers"] * batch * live * s["kv_heads"] * \
+        s["head_dim"] * BF16
+    kv_write = 2 * s["layers"] * batch * s["kv_heads"] * s["head_dim"] * BF16
+    return {"flops": dense + att,
+            "bytes": float(weights + embed_rows + kv_read + kv_write),
+            "attention_flops": att,
+            "attention_bytes": attention_bytes(s, batch, 1, live)}

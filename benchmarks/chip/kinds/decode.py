@@ -145,6 +145,10 @@ class Run:
 
 
 def _insert(cache, part, b0):
-    return {k: jax.lax.dynamic_update_slice_in_dim(cache[k], part[k], b0,
-                                                   axis=1)
-            for k in cache}
+    """``part``, a prefill's cache of a group of requests, written into the
+    batch's ``cache`` from request ``b0`` on: leaf by leaf, whatever the
+    cache's nesting and its kinds of state, each leaf layer-first with the
+    batch on axis 1."""
+    return jax.tree.map(
+        lambda c, p: jax.lax.dynamic_update_slice_in_dim(c, p, b0, axis=1),
+        cache, part)

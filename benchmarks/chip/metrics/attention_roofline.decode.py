@@ -4,8 +4,11 @@ the chip could take for the attention of every step in the window
 the new position and K, V of the live keys read once), over the device
 self time of the ``jit_decode`` ops that the compiled HLO places under the
 named scope ``attention_kernel`` (``models/layers.py``), in percent.  A
-fusion belongs to the scope of its root instruction.  HBM bounds it at
-these shapes."""
+fusion belongs to the scope of its root instruction.  Under the scope lie
+the fetch of each layer's K and V out of the stacked cache, its relayout
+into on-chip memory, and the two dots, which then read on-chip memory, not
+HBM.  The floor is the live KV's bytes at the HBM peak: at these shapes
+the bytes bound the least time, not the FLOPs."""
 
 import trace_reduce
 

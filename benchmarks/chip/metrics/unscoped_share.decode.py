@@ -2,9 +2,11 @@
 model's named scopes: the device self time of the ``jit_decode`` ops whose
 op_name (for a fusion, its root's) is under no scope of ``SCOPES``, over
 the self time of all ``jit_decode`` ops, in percent.  In the decode step
-that is the layer scan's own work: slicing each layer's K and V out of the
-stacked cache, stacking them back, and copying the whole cache around the
-loop.  None for a program that carries none of the scopes.
+that is the layer scan's own work: slicing each layer's weights out of the
+stacked parameters and laying them out for the matmuls.  The fetch of each
+layer's K and V out of the stacked cache is not in it: the scan body reads
+them under ``attention_kernel``.  None for a program that carries none of
+the scopes.
 
 ``SCOPES`` is this reader's own copy of the program's table
 (``models/layers.py:SCOPES``), so that a scope renamed in the program

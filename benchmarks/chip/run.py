@@ -7,11 +7,11 @@ The cell, its model configuration, its traffic mix and its per-layer
 metrics are found by name from ``BENCHMARK.json`` (see ``cell.py``).  The
 run makes its weights and inputs from ``--seed``, sets up (compiles,
 warms up), measures for ``--seconds``, then checks what the window served
-against the float32 reference.  With ``--trace 0`` it reports the cell's
-end-to-end metrics; with ``--trace 1`` it traces the window with the JAX
-profiler and reports the cell's per-layer metrics.  The last line of
-standard output is one JSON object; the numbers that decide ``correct``
-are the last lines of standard error.
+against the float32 reference of its model's family.  With ``--trace 0``
+it reports the cell's end-to-end metrics; with ``--trace 1`` it traces the
+window with the JAX profiler and reports the cell's per-layer metrics.
+The last line of standard output is one JSON object; the numbers that
+decide ``correct`` are the last lines of standard error.
 
 It runs only on a TPU whose ``device_kind`` is in ``peaks.json``; anywhere
 else it exits 2 and prints no result.
@@ -25,7 +25,6 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import glob  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
@@ -42,19 +41,12 @@ sys.path.insert(1, str(HERE.parents[1] / "src"))
 
 import cell as cells  # noqa: E402
 import check  # noqa: E402
+from cell import load_module  # noqa: E402
 import trace_reduce  # noqa: E402
 
 
 class NoChip(RuntimeError):
     pass
-
-
-def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        path.stem.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def find_chips(cell) -> tuple:

@@ -21,7 +21,8 @@ import cell as cells  # noqa: E402
 import check  # noqa: E402
 import run as bench  # noqa: E402
 
-TINY = {"name": "tiny", "hidden_size": 64, "intermediate_size": 128,
+TINY = {"name": "tiny", "family": "dense_gqa",
+        "hidden_size": 64, "intermediate_size": 128,
         "num_attention_heads": 4, "num_key_value_heads": 2,
         "vocab_size": 256, "num_hidden_layers": 2, "rope_theta": 10000.0,
         "rms_norm_eps": 1e-5,
@@ -181,3 +182,57 @@ def test_traced_run_reports_per_layer(monkeypatch):
     assert set(out["metrics"]) == {m["name"] for m in c.per_layer}
     assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
     assert out["breakdown"]["device_ops"] and out["breakdown"]["idle_gaps"]
+
+
+# a family file of its own name that is the dense GQA decoder
+NEW_FAMILY = """from cell import HERE, load_module
+
+_dense = load_module(HERE / "families" / "dense_gqa.py")
+sizes, arch_config, row_logits = (_dense.sizes, _dense.arch_config,
+                                  _dense.row_logits)
+witness, prefill, decode_step = (_dense.witness, _dense.prefill,
+                                 _dense.decode_step)
+"""
+
+
+@pytest.mark.parametrize("fault", [None, "altered"])
+def test_new_family_from_its_own_file(fault, tmp_path, monkeypatch):
+    """A configuration that names a family whose file lies in a directory
+    of its own runs end to end, with the harness looking up families
+    there only, and its check holds: correct as it is, not correct with
+    the served tokens altered."""
+    (tmp_path / "tiny_family.py").write_text(NEW_FAMILY)
+    monkeypatch.setattr(cells, "FAMILIES", tmp_path)
+    with pytest.raises(SystemExit):
+        cells.family("dense_gqa")
+    if fault:
+        _break("decode", fault, monkeypatch)
+    c = tiny_cell("decode", "gelu_pytorch_tanh")
+    c = cells.Cell(**{**c.__dict__,
+                      "config": dict(c.config, family="tiny_family")})
+    assert cells.sizes(c.config)["family"] == "tiny_family"
+    out = bench.run_cell(c, 2**32 + 5, 0.3, False, cpu(), PEAKS)
+    assert out["correct"] is (fault is None), out
+
+
+def test_insert_writes_every_leaf_of_a_nested_cache():
+    """The decode set-up's insert of a group's prefilled cache reaches
+    every leaf of a cache that nests two kinds of state, each with the
+    batch on axis 1, and leaves the other requests' rows as they were."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    mod = bench.load_module(HERE / "kinds" / "decode.py")
+    gen = np.random.default_rng(3)
+
+    def cache(batch):
+        return {"latent": gen.normal(size=(2, batch, 8, 5)),
+                "kv": {"k": gen.normal(size=(2, batch, 8, 2, 3)),
+                       "v": gen.normal(size=(2, batch, 8, 2, 3))}}
+    whole, part = cache(6), cache(2)
+    out = jax.jit(mod._insert)(whole, part, jnp.asarray(2, jnp.int32))
+    for got, was, new in zip(jax.tree.leaves(out), jax.tree.leaves(whole),
+                             jax.tree.leaves(part)):
+        want = was.copy()
+        want[:, 2:4] = new
+        np.testing.assert_array_equal(np.asarray(got), want.astype(got.dtype))
