@@ -7,7 +7,8 @@ that entry names, found by name under this directory:
   ``families/__init__.py``);
 - ``traffic/<traffic>.json``: the parameters of the traffic mix;
 - ``cells/<cell>.json``: how many requests the check samples and the
-  limits of the numbers that decide ``correct``.
+  limits of the numbers that decide ``correct``, which are the numbers
+  compared (``check.unjudged`` says which sets ``load`` refuses).
 
 A new cell adds files and entries; nothing here names a cell or a
 family.
@@ -76,6 +77,11 @@ def load(workload: str, bench: Optional[dict] = None) -> Cell:
     config = next(c for c in bench["configs"] if c["name"] == entry["config"])
     config = read_json(REPO / config["file"])
     family(config["family"])        # stops before set-up where it has no file
+    spec = read_json(HERE / "cells" / f"{workload}.json")
+    import check                    # imports this module
+    why = check.unjudged(spec["limits"])
+    if why:
+        raise SystemExit(f"cells/{workload}.json {why}")
     e2e = tuple(m for m in bench["end_to_end"] if reports(m, workload, ()))
     names = {m["name"] for m in e2e}
     per_layer = tuple(m for m in bench["per_layer"]
@@ -84,7 +90,7 @@ def load(workload: str, bench: Optional[dict] = None) -> Cell:
                 config=config,
                 traffic=read_json(HERE / "traffic" /
                                   f"{entry['traffic']}.json"),
-                spec=read_json(HERE / "cells" / f"{workload}.json"),
+                spec=spec,
                 end_to_end=e2e, per_layer=per_layer)
 
 

@@ -7,12 +7,15 @@ upper readings), in one process so that set-up compiles once.
 
 For each seed it runs the cell's own set-up and window at the cell's own
 load, samples the requests as a run does, and prints one JSON line with
-the numbers ``check.py`` compares for the program (``program``), for the
-control (``control``: the reference computed with float8 operands put in
-the program's place) and, for ``max_logit_gap``, for each served token
-altered to the next id (``altered``).  The benchmark's runs never run
-this.  With ``--witness`` it also compares the program's weights with the
-ones the reference makes from the same seed (the family's ``witness``).
+every number ``check.py`` computes (``logit_rel_rms``,
+``median_logit_rel_rms``, ``max_logit_gap``), whichever of them the
+cell's file limits, for the program (``program``), for the control
+(``control``: the reference computed with float8 operands put in the
+program's place) and, for ``max_logit_gap``, for each served token
+altered to the next id (``altered``), so that a cell's limits can be set
+from them.  The benchmark's runs never run this.  With ``--witness`` it
+also compares the program's weights with the ones the reference makes
+from the same seed (the family's ``witness``).
 """
 
 from __future__ import annotations

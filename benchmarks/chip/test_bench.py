@@ -44,9 +44,20 @@ def limits(kind: str) -> dict:
     return cells.read_json(HERE / "cells" / f"{CELLS[kind]}.json")["limits"]
 
 
-def tiny_cell(kind: str, act: str, window: int = 0) -> cells.Cell:
+def median_row(own: dict) -> dict:
+    """A cell's limits moved from the worst row to each request's median
+    row, as a routed model's cell has them: the worst row's limit put on
+    the median row."""
+    return {"median_logit_rel_rms": own["logit_rel_rms"],
+            "max_logit_gap": own["max_logit_gap"]}
+
+
+def tiny_cell(kind: str, act: str, window: int = 0,
+              judged_by: str = "worst_row") -> cells.Cell:
     config = dict(TINY, hidden_act=act, sliding_window=window)
-    spec = {"check": {"requests": 3}, "limits": limits(kind)}
+    own = limits(kind)
+    spec = {"check": {"requests": 3},
+            "limits": own if judged_by == "worst_row" else median_row(own)}
     e2e = ({"name": f"{kind}_tokens_per_s", "unit": "tokens/s"},
            {"name": "setup_s", "unit": "s"})
     return cells.Cell(name=f"tiny.{kind}", chips=1, config=config,
@@ -134,13 +145,15 @@ def test_broken_path_is_not_correct(kind, act, fault, monkeypatch):
     assert not out["correct"], out
 
 
+@pytest.mark.parametrize("judged_by", ["worst_row", "median_row"])
 @pytest.mark.parametrize("seed", [5, 2**31 + 3, 77])
 @pytest.mark.parametrize("kind,act,window", CASES)
-def test_control_is_not_correct(kind, act, window, seed):
+def test_control_is_not_correct(kind, act, window, seed, judged_by):
     """The reference computed with float8 operands, put in the program's
     place at the rows a run samples, is judged not correct under the
-    cell's own limits, where the program is judged correct."""
-    c = tiny_cell(kind, act, window)
+    cell's own limits, where the program is judged correct; and so under
+    the same limits put on the median row."""
+    c = tiny_cell(kind, act, window, judged_by)
     mod = bench.load_module(HERE / "kinds" / f"{kind}.py")
     run = mod.Run(c, seed)
     run.window(0.3)
@@ -195,24 +208,33 @@ witness, prefill, decode_step = (_dense.witness, _dense.prefill,
 """
 
 
+@pytest.mark.parametrize("judged_by", ["worst_row", "median_row"])
 @pytest.mark.parametrize("fault", [None, "altered"])
-def test_new_family_from_its_own_file(fault, tmp_path, monkeypatch):
+def test_new_family_from_its_own_file(fault, judged_by, tmp_path,
+                                      monkeypatch, capsys):
     """A configuration that names a family whose file lies in a directory
     of its own runs end to end, with the harness looking up families
     there only, and its check holds: correct as it is, not correct with
-    the served tokens altered."""
+    the served tokens altered.  It compares and prints exactly the numbers
+    its cell's file limits, whether the worst row or, as a routed model's
+    cell does, each request's median row."""
     (tmp_path / "tiny_family.py").write_text(NEW_FAMILY)
     monkeypatch.setattr(cells, "FAMILIES", tmp_path)
     with pytest.raises(SystemExit):
         cells.family("dense_gqa")
     if fault:
         _break("decode", fault, monkeypatch)
-    c = tiny_cell("decode", "gelu_pytorch_tanh")
+    c = tiny_cell("decode", "gelu_pytorch_tanh", judged_by=judged_by)
     c = cells.Cell(**{**c.__dict__,
                       "config": dict(c.config, family="tiny_family")})
     assert cells.sizes(c.config)["family"] == "tiny_family"
+    capsys.readouterr()
     out = bench.run_cell(c, 2**32 + 5, 0.3, False, cpu(), PEAKS)
     assert out["correct"] is (fault is None), out
+    assert set(out["compared"]) == set(c.limits)
+    printed = [line.split()[1] for line in capsys.readouterr().err.splitlines()
+               if line.startswith("compared ")]
+    assert sorted(printed) == sorted(c.limits)
 
 
 def test_insert_writes_every_leaf_of_a_nested_cache():
