@@ -177,6 +177,8 @@ def cache_specs(abstract_cache, cfg: ArchConfig, mesh: Mesh):
     """Decode caches: batch over 'data' if divisible; the long axis
     (cache sequence / window / state heads) over 'model'."""
 
+    from ..models import api       # models import this package's hints
+
     def one(path, leaf):
         shape = leaf.shape
         name = path[-1]
@@ -188,8 +190,9 @@ def cache_specs(abstract_cache, cfg: ArchConfig, mesh: Mesh):
         if _div(shape[bdim], mesh, "data"):
             spec[bdim] = "data"
         if name in ("k", "v", "mk", "mv") and len(shape) == 5:
-            if _div(shape[2], mesh, "model"):
-                spec[2] = "model"          # cache sequence (SP decode)
+            seq = api.kv_seq_axis(cfg) if name in ("k", "v") else 2
+            if _div(shape[seq], mesh, "model"):
+                spec[seq] = "model"        # cache sequence (SP decode)
         elif name == "S" and len(shape) == 5:
             if _div(shape[2], mesh, "model"):
                 spec[2] = "model"          # rwkv heads

@@ -6,6 +6,7 @@ Functions every family provides (shapes in family modules):
   prefill(params, cfg, batch)                    -> (cache, logits)
   decode_step(params, cfg, token, pos, cache)    -> (logits, cache)
   init_cache(cfg, batch, max_len)                -> cache           [decode]
+  kv_seq_axis(cfg)       -> the sequence axis of a key/value cache's k, v
 plus ``input_specs`` / ``make_batch`` describing the inputs of each shape
 kind (tokens, labels, stub frame/patch embeddings).
 """
@@ -141,6 +142,18 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int):
     if cfg.family == "encdec":
         return encdec.init_cache(cfg, batch, max_len, mem_len=4096)
     raise ValueError(cfg.family)
+
+
+def kv_seq_axis(cfg: ArchConfig) -> int:
+    """The sequence axis of the ``k`` and ``v`` leaves of a decode cache:
+    head-major (L, B, Kh, S, Dh) in the decoder families; (L, B, S, Kh, Dh)
+    in encdec, and in hybrid's local-attention ring, whose S is its
+    window."""
+    if cfg.family in ("dense", "moe", "vlm"):
+        return transformer.KV_SEQ_AXIS
+    if cfg.family in ("encdec", "hybrid"):
+        return 2
+    raise ValueError(f"{cfg.family} keeps no key/value cache")
 
 
 def prefill(params: Params, cfg: ArchConfig, batch: Dict):

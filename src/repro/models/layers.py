@@ -208,6 +208,55 @@ def _decode_attention(q, k, v, *, causal, q_offset, window, kv_len):
 
 
 @scope("attention_kernel")
+def decode_attention(q, k_cache, v_cache, k, v, *, pos, window: int = 0):
+    """The decode step's queries at ``pos``.. against the cached keys before
+    ``pos`` and the step's own ``k``/``v``, attended to apart and merged by
+    the online-softmax rule, so the cache is only read: nothing is written
+    into it first, and what it holds at ``pos`` and after is never read.
+
+    q, k, v: (B, T, H | Kh, Dh); k_cache, v_cache: (B, Kh, S, Dh),
+    head-major as the decode cache is stored.  The same keys and softmax as
+    ``attention`` over the cache with ``k``/``v`` written at ``pos``
+    (``q_offset=pos``, ``kv_len=pos + T``, causal).
+    """
+    B, T, H, Dh = q.shape
+    Kh, S = k_cache.shape[1], k_cache.shape[2]
+    G = H // Kh
+    q = hints.constrain(q, "dp", None, None, None)
+    k_cache = hints.constrain(k_cache, "dp", None, "spm", None)
+    v_cache = hints.constrain(v_cache, "dp", None, "spm", None)
+    qs = (q * (1.0 / math.sqrt(Dh))).reshape(B, T, Kh, G, Dh)
+    q_pos = pos + jnp.arange(T)
+    # the cached part: positions before pos, inside each query's window
+    pos_k = jnp.arange(S)
+    mask = jnp.broadcast_to(pos_k[None, :] < pos, (T, S))
+    if window:
+        mask = mask & (pos_k[None, :] > q_pos[:, None] - window)
+    s = jnp.einsum("btkgd,bksd->bkgts", qs, k_cache,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(mask[None, None, None], s, NEG_INF)
+    # the step's own part: query t sees the step's keys up to t
+    new_k = q_pos[None, :]
+    mask_new = new_k <= q_pos[:, None]
+    if window:
+        mask_new = mask_new & (new_k > q_pos[:, None] - window)
+    s_new = jnp.einsum("btkgd,bukd->bkgtu", qs, k,
+                       preferred_element_type=jnp.float32)
+    s_new = jnp.where(mask_new[None, None, None], s_new, NEG_INF)
+    # each part's max and sum, merged; the cached part's reduce over a
+    # sequence-sharded cache is each shard's partial, then one combine
+    m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True),
+                    jnp.max(s_new, axis=-1, keepdims=True))
+    p, p_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    l = jnp.sum(p, axis=-1, keepdims=True) + jnp.sum(p_new, axis=-1,
+                                                     keepdims=True)
+    o = (jnp.einsum("bkgts,bksd->btkgd", (p / l).astype(v_cache.dtype),
+                    v_cache).astype(jnp.float32)
+         + jnp.einsum("bkgtu,bukd->btkgd", p_new / l, v.astype(jnp.float32)))
+    return o.astype(q.dtype).reshape(B, T, H, Dh)
+
+
+@scope("attention_kernel")
 def _attention_inner(q, k, v, *, causal, q_offset, window, kv_len, block,
                      head_sharded):
     """The part the Pallas flash kernel replaces on TPU — wrapped in a

@@ -163,9 +163,15 @@ def forward(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
 # serving: cache init / prefill / decode
 # ---------------------------------------------------------------------------
 
+# The KV cache is stored head-major, (L, B, Kh, S, Dh): each layer's slab
+# is then in the layout the decode attention's dots read, so they read it
+# where it lies in the stacked cache, with no copy of the layer first.
+KV_SEQ_AXIS = 3
+
+
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Params:
     dt = _dtype(cfg)
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.hd)
+    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.hd)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
@@ -177,7 +183,7 @@ def prefill(params: Params, cfg: ArchConfig, tokens: jnp.ndarray,
                        remat=False)
     with L.scope("lm_head"):
         logits = x[:, -1:] @ lm_head(params, cfg)
-    return {"k": kv[0], "v": kv[1]}, logits
+    return {"k": kv[0].swapaxes(2, 3), "v": kv[1].swapaxes(2, 3)}, logits
 
 
 def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
@@ -195,22 +201,20 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
     def body(carry, pl):
         x, i = carry
         # fetching the layer's K/V from the stacked cache is the attention's
-        # traffic: the compiler stages it into on-chip memory for the dots
+        # traffic: the compiler fuses the slice into the dots that read it
         with L.scope("attention_kernel"):
             kc, vc = (jax.lax.dynamic_index_in_dim(cache[name], i,
                                                    keepdims=False)
                       for name in ("k", "v"))
-        kc = hints.constrain(kc, "dp", "model", None, None)
-        vc = hints.constrain(vc, "dp", "model", None, None)
+        kc = hints.constrain(kc, "dp", None, "model", None)
+        vc = hints.constrain(vc, "dp", None, "model", None)
         h = L.rmsnorm(x, pl["ln1"], cfg.norm_eps)
         q, k, v = L.gqa_project(h, pl["attn"], cfg.n_heads, cfg.n_kv_heads,
                                 cfg.hd, positions, cfg.rope_theta)
         k, v = k.astype(kc.dtype), v.astype(vc.dtype)
-        with L.scope("kv_cache_write"):
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=1)
-        o = L.attention(q, kc, vc, causal=False, q_offset=pos,
-                        window=cfg.window, kv_len=pos + 1)
+        # the cache is only read here: a write into the layer's slice would
+        # make the compiler copy the whole slice out of the stacked cache
+        o = L.decode_attention(q, kc, vc, k, v, pos=pos, window=cfg.window)
         x = _attn_out(x, o, pl["attn"]["wo"])
         h2 = L.rmsnorm(x, pl["ln2"], cfg.norm_eps)
         if cfg.moe:
@@ -227,8 +231,8 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
     (x, _), (k_new, v_new) = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.int32)), params["layers"])
     with L.scope("kv_cache_write"):
-        cache = {name: jax.lax.dynamic_update_slice_in_dim(cache[name], new,
-                                                           pos, axis=2)
+        cache = {name: jax.lax.dynamic_update_slice_in_dim(
+                     cache[name], new.swapaxes(2, 3), pos, axis=KV_SEQ_AXIS)
                  for name, new in (("k", k_new), ("v", v_new))}
     x = L.rmsnorm(x, params["norm_f"], cfg.norm_eps)
     with L.scope("lm_head"):

@@ -70,10 +70,11 @@ def make_prefill_step(cfg: ArchConfig, max_len: Optional[int] = None):
         cache, logits = api.prefill(params, cfg, batch)
         if max_len is not None and kv_cache:
             cache = dict(cache)
-            for name in ("k", "v"):        # (L, B, S, Kh, Dh)
+            seq = api.kv_seq_axis(cfg)
+            for name in ("k", "v"):
                 c = cache[name]
                 pad = [(0, 0)] * c.ndim
-                pad[2] = (0, max_len - c.shape[2])
+                pad[seq] = (0, max_len - c.shape[seq])
                 cache[name] = jnp.pad(c, pad)
         return cache, logits
 

@@ -86,9 +86,9 @@ def _padded_cache(cfg, params, batch, max_len=64):
         full["mk"], full["mv"] = cache["mk"], cache["mv"]
         return full
     full = api.init_cache(cfg, batch["tokens"].shape[0], max_len)
-    S = cache["k"].shape[2]
-    return {"k": full["k"].at[:, :, :S].set(cache["k"]),
-            "v": full["v"].at[:, :, :S].set(cache["v"])}
+    return {name: jax.lax.dynamic_update_slice_in_dim(
+                full[name], cache[name], 0, axis=api.kv_seq_axis(cfg))
+            for name in ("k", "v")}
 
 
 @pytest.mark.parametrize("arch", ["llama3-8b", "rwkv6-7b",

@@ -10,6 +10,7 @@ this file.
 """
 
 import dataclasses
+import math
 import os
 import re
 
@@ -77,26 +78,43 @@ def test_kernel_compiles_for_v5e(one_chip, name, fn, shapes):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_decode_writes_the_donated_cache_in_place(one_chip):
-    """The decode step at StarCoder2-7B widths (2 layers, batch 16, a 4096
-    cache) hands the donated cache back as its output and makes no second
-    one: no whole-cache copy or buffer in the entry computation."""
-    cfg = dataclasses.replace(model_config("starcoder2-7b", layers=2),
-                              window=4096)
-    B, S = 16, 4096
-    _, decode = serve.build(cfg, S)
+@pytest.fixture(scope="module")
+def decode_program(one_chip):
+    """The serve driver's decode step at StarCoder2-7B widths (batch 16, a
+    4096 cache), compiled once per depth: ``decode_program(layers)`` gives
+    (compiled, params, cache) with the arguments as shapes."""
+    done = {}
 
-    def on_chip(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
+    def build(layers):
+        if layers in done:
+            return done[layers]
+        cfg = dataclasses.replace(model_config("starcoder2-7b",
+                                               layers=layers), window=4096)
+        B, S = 16, 4096
+        _, decode = serve.build(cfg, S)
 
-    params = on_chip(jax.eval_shape(lambda k: api.init_params(k, cfg),
-                                    jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(lambda: api.init_cache(cfg, B, S)))
-    compiled = decode.lower(
-        params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32,
-                                            sharding=one_chip),
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+        def on_chip(tree):
+            return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        params = on_chip(jax.eval_shape(lambda k: api.init_params(k, cfg),
+                                        jax.random.PRNGKey(0)))
+        cache = on_chip(jax.eval_shape(lambda: api.init_cache(cfg, B, S)))
+        compiled = decode.lower(
+            params, cache, jax.ShapeDtypeStruct((B, 1), jnp.int32,
+                                                sharding=one_chip),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+        done[layers] = compiled, params, cache
+        return done[layers]
+
+    return build
+
+
+def test_decode_writes_the_donated_cache_in_place(decode_program):
+    """The decode step, 2 layers deep, hands the donated cache back as its
+    output and makes no second one: no whole-cache copy or buffer in the
+    entry computation."""
+    compiled, params, cache = decode_program(2)
     hlo = compiled.as_text()
 
     # outputs (logits, cache k, cache v, token); the cache's parameters
@@ -114,3 +132,23 @@ def test_decode_writes_the_donated_cache_in_place(one_chip):
         assert " copy(" not in line and "AllocateBuffer" not in line, line
         assert not re.match(r"\s*(ROOT )?%copy", line), line
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+# 16: the depth of the benchmark's decode cell, where the compiler has more
+# to place in on-chip memory than at 2
+@pytest.mark.parametrize("layers", [2, 16])
+def test_decode_scan_reads_each_layer_in_place(decode_program, layers):
+    """The layer scan's attention reads each layer's K and V where they lie
+    in the stacked cache, or stages them in on-chip memory (memory space
+    S(1)): no instruction of the scan body makes a whole layer's K or V in
+    HBM, and the program's scratch holds less than one of them."""
+    compiled, _, cache = decode_program(layers)
+    hlo = compiled.as_text()
+    body = re.search(r" while\(.*?body=%([\w.\-]+)", hlo).group(1)
+    comp = hlo[hlo.index(f"\n%{body} "):].split("\n}\n")[0]
+    slab = math.prod(cache["k"].shape[1:])      # elements of a layer's K
+    for line in comp.splitlines()[1:]:
+        m = re.match(r"\s*(ROOT )?%\S+ = bf16\[([\d,]+)\]\{([^}]*)\}", line)
+        if m and math.prod(map(int, m.group(2).split(","))) == slab:
+            assert "S(1)" in m.group(3), line
+    assert compiled.memory_analysis().temp_size_in_bytes < slab * 2
